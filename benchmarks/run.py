@@ -28,6 +28,8 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated bench names (e.g. throughput,roofline)")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from benchmarks import (bench_breakdown, bench_cache_sensitivity,
                             bench_convergence, bench_fabric,

@@ -16,6 +16,7 @@ import argparse
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.train import train_loop
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

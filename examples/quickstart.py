@@ -17,6 +17,7 @@ import dataclasses
 from repro.gns import EngineConfig, GNSEngine
 from repro.gns.config import DataConfig
 from repro.graph.datasets import get_dataset
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--batch-size", type=int, default=128)
     ap.add_argument("--max-batches", type=int, default=30)
     args = ap.parse_args()
+    use_compile_cache()
 
     base = EngineConfig.preset(
         "quickstart",

@@ -27,6 +27,7 @@ from repro.featurestore import CacheConfig
 from repro.gns import (EngineConfig, FabricConfig, GNSEngine, ServeConfig,
                        TenantConfig)
 from repro.gns.config import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.serve import QueueFull
 
 
@@ -38,6 +39,7 @@ def main():
     ap.add_argument("--kill-worker", action="store_true",
                     help="kill worker 0 mid-stream to exercise failover")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = EngineConfig(
         sampler="gns",

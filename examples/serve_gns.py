@@ -19,6 +19,7 @@ from repro.core.sampler import SamplerConfig
 from repro.featurestore import CacheConfig
 from repro.gns import EngineConfig, GNSEngine, ServeConfig
 from repro.gns.config import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -29,6 +30,7 @@ def main():
     ap.add_argument("--hot-share", type=float, default=0.9,
                     help="fraction of requests drawn from the hot set")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = EngineConfig(
         sampler="gns",

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.serve import Request, ServeEngine
 from repro.models.lm import get_model
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--max-new", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_config(args.arch).reduced()      # CPU container scale
     model = get_model(cfg)

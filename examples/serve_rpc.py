@@ -17,10 +17,15 @@ here everything runs on localhost so the example is self-contained):
 
 By default this script spawns the endpoints as REAL subprocesses (the
 honest cross-host rehearsal: separate interpreters, separate caches, bytes
-on a socket).  ``--in-thread`` serves them on threads instead, which is
-faster to start when you just want to see the API.  ``--kill-endpoint``
-SIGKILLs endpoint 0 mid-stream to show lossless failover onto the
-survivor.
+on a socket).  A chip belongs to one process, so on an accelerator host
+each endpoint process is given a chip of its own (``TPU_VISIBLE_CHIPS``),
+the script refuses to start more endpoints than the host has chips, and the
+coordinator — which only routes — keeps its engine on the CPU.  This
+process never touches the chips itself: a short-lived child counts them.
+``--in-thread`` serves the endpoints on threads of this one process
+instead, which is faster to start when you just want to see the API.
+``--kill-endpoint`` SIGKILLs endpoint 0 mid-stream to show lossless
+failover onto the survivor.
 
 Run:  PYTHONPATH=src python examples/serve_rpc.py [--requests 100]
 """
@@ -35,6 +40,7 @@ import sys
 import tempfile
 import time
 
+import jax
 import numpy as np
 
 from repro.core.sampler import SamplerConfig
@@ -42,6 +48,7 @@ from repro.featurestore import CacheConfig
 from repro.gns import (EngineConfig, FabricConfig, GNSEngine, ServeConfig,
                        TenantConfig)
 from repro.gns.config import DataConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _engine_config(scale: float) -> EngineConfig:
@@ -53,8 +60,35 @@ def _engine_config(scale: float) -> EngineConfig:
         serve=ServeConfig(buckets=(16, 64), max_wait_ms=2.0))
 
 
+def _host_devices() -> tuple[str, int]:
+    """(platform, device count) as JAX sees them on this host, asked of a
+    short-lived child: the process that touches JAX holds the chips."""
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        capture_output=True, text=True, check=True)
+    platform, count = out.stdout.split()[-2:]
+    return platform, int(count)
+
+
+def _endpoint_env(index: int, platform: str) -> dict:
+    env = dict(os.environ, PYTHONPATH="src")
+    if platform == "tpu":                 # one chip per endpoint process
+        env.update(TPU_VISIBLE_CHIPS=str(index),
+                   TPU_CHIPS_PER_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_BOUNDS="1,1,1",
+                   TPU_PROCESS_PORT=str(8476 + index))
+    return env
+
+
 def _spawn_subprocess_endpoints(cfg: EngineConfig, n: int):
-    """One ``python -m repro.rpc.endpoint`` process per replica."""
+    """One ``python -m repro.rpc.endpoint`` process per replica, each on a
+    chip of its own where the host has accelerators."""
+    platform, count = _host_devices()
+    if platform != "cpu" and count < n:
+        sys.exit(f"serve_rpc: {n} endpoint processes need a {platform} chip "
+                 f"each, and this host has {count}; pass --endpoints "
+                 f"{count} or --in-thread")
     fd, cfg_path = tempfile.mkstemp(suffix=".json")
     with os.fdopen(fd, "w") as f:
         json.dump(cfg.to_dict(), f)
@@ -63,11 +97,15 @@ def _spawn_subprocess_endpoints(cfg: EngineConfig, n: int):
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "repro.rpc.endpoint",
              "--config", cfg_path, "--index", str(i), "--port", "0"],
-            env=dict(os.environ, PYTHONPATH="src"),
+            env=_endpoint_env(i, platform),
             stdout=subprocess.PIPE, text=True))
-    for p in procs:
+    for i, p in enumerate(procs):
         line = p.stdout.readline()           # blocks until the replica is up
-        assert "GNS_ENDPOINT_READY" in line, line
+        if "GNS_ENDPOINT_READY" not in line:
+            for q in procs:
+                q.kill()
+            sys.exit(f"serve_rpc: endpoint {i} exited before it was ready "
+                     f"(exit code {p.wait()})")
         ports.append(int(dict(kv.split("=")
                               for kv in line.split()[1:])["port"]))
         print(f"  endpoint up: pid={p.pid} port={ports[-1]}")
@@ -95,6 +133,7 @@ def main():
     ap.add_argument("--kill-endpoint", action="store_true",
                     help="SIGKILL endpoint 0 mid-stream (subprocess mode)")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = _engine_config(args.scale)
     print(f"starting {args.endpoints} endpoint replicas ...")
@@ -104,6 +143,9 @@ def main():
         ports = [ep.port for ep in eps]
     else:
         procs, ports, _ = _spawn_subprocess_endpoints(cfg, args.endpoints)
+        # the coordinator only routes: its engine stays off the chips the
+        # endpoint processes hold
+        jax.config.update("jax_platforms", "cpu")
 
     try:
         coordinator = GNSEngine(cfg)

@@ -26,6 +26,7 @@ from repro.core.sampler import SamplerConfig
 from repro.featurestore import CacheConfig
 from repro.gns import EngineConfig, GNSEngine
 from repro.gns.config import DataConfig, MeshConfig, ModelConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--prefetch", action="store_true", default=True)
     args = ap.parse_args()
+    use_compile_cache()
 
     mesh_cfg, model_cfg = None, ModelConfig()
     if args.mesh:

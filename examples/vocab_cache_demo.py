@@ -18,6 +18,7 @@ import numpy as np
 from repro.featurestore import TrafficMeter
 from repro.data.tokens import SyntheticCorpus
 from repro.data.vocab_cache import VocabCache, VocabCacheConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def main():
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--zipf", type=float, default=1.2)
     args = ap.parse_args()
+    use_compile_cache()
 
     rng = np.random.default_rng(0)
     table = rng.standard_normal((args.vocab, args.dim)).astype(np.float32)

@@ -374,7 +374,7 @@ def find_trace_roots(index: RepoIndex) -> List[TraceRoot]:
             r = index.resolve(mi, d)
             if r is None and "." not in d:
                 # nested function referenced from its enclosing scope:
-                # fn = shard_map_compat(body, ...) where `body` is a local def
+                # fn = jax.shard_map(body, ...) where `body` is a local def
                 scope = _scope_of(target, mi)
                 while scope is not None:
                     cand = f"{scope}.{d}"
@@ -458,8 +458,7 @@ def find_trace_roots(index: RepoIndex) -> List[TraceRoot]:
                     _extract_statics(node), node)
             elif d.endswith("pallas_call") and node.args:
                 add(mi, node.args[0], "pallas", node.lineno)
-            elif d in ("shard_map", "shard_map_compat") \
-                    or d.endswith(".shard_map"):
+            elif d == "shard_map" or d.endswith(".shard_map"):
                 if node.args:
                     add(mi, node.args[0], "shard_map", node.lineno)
     return roots

@@ -12,7 +12,7 @@ in HBM.  This kernel fuses both: the *scalar-prefetched* ``idx`` and
 pre-gathered per-lane ``slots[idx]`` arrays (both [B, K] — the full [S0]
 slot map would blow SMEM at paper scale) drive the BlockSpec index maps of
 BOTH source operands — per grid step the pipeline DMAs one (1, block_d)
-tile from the cache table at row ``max(slots[idx[b,k]], 0)`` and one from
+row tile from the cache table at row ``max(slots[idx[b,k]], 0)`` and one from
 the streamed buffer at row ``idx[b,k]``, and the VPU selects the live lane
 and accumulates.  h0 never exists in memory.
 
@@ -55,17 +55,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gather_agg import (block_width, chunk_lanes, lane_chunks,
+                                     take_lanes)
+
 
 def _kernel(idx_ref, lane_slots_ref, w_ref, cache_ref, streamed_ref, out_ref):
     b = pl.program_id(0)
     k = pl.program_id(2)
+    lane = k * pl.num_programs(0) + b           # k-major lane order
 
     @pl.when(k == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    hit = lane_slots_ref[b, k] >= 0
-    w = w_ref[b, k]
+    hit = lane_slots_ref[lane] >= 0
+    w = w_ref[lane]
     # both candidate tiles were DMA'd by the index maps; select on the VPU.
     # Accumulation order is fixed (K innermost, ascending) and matches the
     # sequential reference; XLA may contract the mul+add into an FMA, so
@@ -89,50 +93,56 @@ def cache_lookup_agg_pallas(cache_table: jax.Array, streamed: jax.Array,
       idx:         [B, K] int32 input-row indices (padded lanes carry w == 0).
       w:           [B, K] f32 aggregation weights.
     Returns [B, D] f32.
+
+    Row tiles, SMEM lane chunks and the k-major lane order follow
+    ``kernels/gather_agg.py``.
     """
-    _, d = cache_table.shape
+    c, d = cache_table.shape
+    s0 = streamed.shape[0]
     assert streamed.shape[1] == d
     bsz, num_k = idx.shape
-    block_d = min(block_d, d)
-    while d % block_d:          # largest divisor <= requested block
-        block_d -= 1
-    grid = (bsz, d // block_d, num_k)
+    block_d = block_width(d, block_d)
+    rows, chunks = lane_chunks(bsz, num_k)
 
-    # Pre-gather the per-lane slots to [B, K] on the XLA side: SMEM then
-    # holds only the two small lane arrays (4·B·K bytes each), never the
-    # full [S0] slot map (~700 KB at the paper's 176k-row input layer,
-    # beyond TPU SMEM).
-    lane_slots = jnp.take(slots.astype(jnp.int32), idx.astype(jnp.int32),
-                          axis=0)
+    # Pre-gather the per-lane slots on the XLA side: SMEM then holds only
+    # lane arrays, never the full [S0] slot map (~700 KB at the paper's
+    # 176k-row input layer, beyond TPU SMEM).  Gathered as [K, B]: see
+    # ``chunk_lanes``.
+    idx_t = idx.astype(jnp.int32).T
+    slots_t = jnp.take(slots.astype(jnp.int32), idx_t, axis=0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,               # idx + lane_slots ride in SMEM
-        grid=grid,
+        num_scalar_prefetch=3,          # idx + lane_slots + w ride in SMEM
+        grid=(rows, d // block_d, num_k),
         in_specs=[
-            # weights: full (B, K) in VMEM — tiny (4·B·K bytes)
-            pl.BlockSpec((bsz, num_k),
-                         lambda b, db, k, idx_ref, sl_ref: (0, 0)),
             # cache rows: slot of the gathered input row (clamped for misses —
             # the dead tile is discarded by the select)
-            pl.BlockSpec((1, block_d),
-                         lambda b, db, k, idx_ref, sl_ref:
-                         (jnp.maximum(sl_ref[b, k], 0), db)),
+            pl.BlockSpec((None, 1, block_d),
+                         lambda b, db, k, idx_ref, sl_ref, w_ref:
+                         (jnp.maximum(sl_ref[k * rows + b], 0), 0, db)),
             # streamed rows: the gathered input row itself
-            pl.BlockSpec((1, block_d),
-                         lambda b, db, k, idx_ref, sl_ref:
-                         (idx_ref[b, k], db)),
+            pl.BlockSpec((None, 1, block_d),
+                         lambda b, db, k, idx_ref, sl_ref, w_ref:
+                         (idx_ref[k * rows + b], 0, db)),
         ],
-        out_specs=pl.BlockSpec((1, block_d),
-                               lambda b, db, k, idx_ref, sl_ref: (b, db)),
+        out_specs=pl.BlockSpec((None, 1, block_d),
+                               lambda b, db, k, idx_ref, sl_ref, w_ref:
+                               (b, 0, db)),
     )
-    fn = pl.pallas_call(
+    call = pl.pallas_call(
         _kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, d), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((rows, 1, d), jnp.float32),
         interpret=interpret,
     )
-    return fn(idx.astype(jnp.int32), lane_slots,
-              w.astype(jnp.float32), cache_table, streamed)
+    cache3 = cache_table.reshape(c, 1, d)
+    streamed3 = streamed.reshape(s0, 1, d)
+    out = jax.lax.map(
+        lambda lanes: call(*lanes, cache3, streamed3),
+        (chunk_lanes(idx_t, rows, chunks),
+         chunk_lanes(slots_t, rows, chunks),
+         chunk_lanes(w.astype(jnp.float32).T, rows, chunks)))
+    return out.reshape(rows * chunks, d)[:bsz]
 
 
 # ---------------------------------------------------------------------------
@@ -199,7 +209,7 @@ def cache_lookup_agg_shard_partial(local_table: jax.Array,
     if claim_all:
         w_eff = w.astype(jnp.float32)
     else:
-        lane_slots = jnp.take(slots.astype(jnp.int32), idx, axis=0)
+        lane_slots = take_lanes(slots.astype(jnp.int32), idx)
         w_eff = shard_lane_weights(w, lane_slots, shard, rows_per_shard)
     if not use_kernel:
         from repro.kernels import ref

@@ -20,7 +20,7 @@ import numpy as np
 from repro.kernels import ref
 from repro.kernels.cache_lookup import cache_lookup_agg_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
-from repro.kernels.gather_agg import gather_agg_pallas
+from repro.kernels.gather_agg import block_width, gather_agg_pallas, take_lanes
 
 
 def _interpret() -> bool:
@@ -43,11 +43,8 @@ def gather_agg(feat: jax.Array, idx: jax.Array, w: jax.Array,
     """Fused gather + weighted aggregation (GNS hot-spot).  [B,D] f32."""
     if impl == "reference":
         return ref.gather_agg_ref(feat, idx, w)
-    d = feat.shape[1]
-    bd = min(block_d, d)
-    while d % bd:
-        bd -= 1
-    return gather_agg_pallas(feat, idx, w, block_d=bd, interpret=_interpret())
+    return gather_agg_pallas(feat, idx, w, block_d=block_d,
+                             interpret=_interpret())
 
 
 def _dp_spec(mesh, shard_axis):
@@ -106,7 +103,6 @@ def _fused_forward(cache_table, streamed, slots, idx, w, local_shards,
     use_kernel = impl != "reference"
     if mesh is not None and shard_axis in mesh.axis_names:
         from jax.sharding import PartitionSpec as P
-        from repro.launch.sharding import shard_map_compat
 
         n = mesh.shape[shard_axis]
         rows = cache_table.shape[0]
@@ -124,7 +120,7 @@ def _fused_forward(cache_table, streamed, slots, idx, w, local_shards,
                 shard = jax.lax.axis_index(shard_axis)
                 ls = lsv[0]                  # this group's home shard or -1
                 fast = ls >= 0
-                lane_slots = jnp.take(sl.astype(jnp.int32), ix, axis=0)
+                lane_slots = take_lanes(sl.astype(jnp.int32), ix)
                 # fast: claim-all weights (owner serves hits AND misses);
                 # slow: the usual owner-per-lane masking, psum reassembles
                 w_eff = jnp.where(fast, ww.astype(jnp.float32),
@@ -149,8 +145,8 @@ def _fused_forward(cache_table, streamed, slots, idx, w, local_shards,
                 # owner partial bitwise and only the owner paid the kernel
                 return jax.lax.psum(part, shard_axis)
 
-            fn = shard_map_compat(
-                body, mesh=mesh,
+            fn = jax.shard_map(
+                body, mesh=mesh, check_vma=False,
                 in_specs=(P(shard_axis, None), P(bspec, None), P(bspec),
                           P(bspec, None), P(bspec, None), P(bspec)),
                 out_specs=P(bspec, None))
@@ -200,8 +196,8 @@ def _fused_forward(cache_table, streamed, slots, idx, w, local_shards,
                     interpret=_interpret(), use_kernel=use_kernel)
                 return jax.lax.psum(part, shard_axis)
 
-        fn = shard_map_compat(
-            body, mesh=mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(P(shard_axis, None), P(bspec, None), P(bspec),
                       P(bspec, None), P(bspec, None)),
             out_specs=P(bspec, None))
@@ -255,7 +251,6 @@ def _fused_bwd(impl, block_d, mesh, shard_axis, local_shard, dynamic, res, g):
 
     if mesh is not None and shard_axis in mesh.axis_names:
         from jax.sharding import PartitionSpec as P
-        from repro.launch.sharding import shard_map_compat
 
         n = mesh.shape[shard_axis]
         rps = cache_table.shape[0] // n
@@ -267,7 +262,9 @@ def _fused_bwd(impl, block_d, mesh, shard_axis, local_shard, dynamic, res, g):
 
             shard = jax.lax.axis_index(shard_axis)
             gg = gg.astype(jnp.float32)
-            lane_slots = jnp.take(sl.astype(jnp.int32), ix, axis=0)  # [b, k]
+            # lanes k-major ([k, b, ...], see kernels/gather_agg.py)
+            ix_t = ix.T
+            lane_slots = jnp.take(sl.astype(jnp.int32), ix_t, axis=0)
             # the lane-claim rule (owner for hits, shard 0 for misses) and
             # the local-row mapping come from the SAME helpers the forward
             # kernel uses — forward and backward cannot desync
@@ -277,15 +274,15 @@ def _fused_bwd(impl, block_d, mesh, shard_axis, local_shard, dynamic, res, g):
             claim = shard_lane_weights(jnp.ones_like(lane_slots, jnp.float32),
                                        lane_slots, shard, rps)       # 0/1
             rows_own = jnp.take(tbl, jnp.maximum(lane_local, 0), axis=0)
-            rows_miss = jnp.take(st, ix, axis=0)
+            rows_miss = jnp.take(st, ix_t, axis=0)
             # each lane's h0 comes from exactly one shard (the claim mask) —
             # the psum below reassembles it, like the forward
             h0_part = jnp.where(own[..., None],
                                 rows_own.astype(jnp.float32),
                                 rows_miss.astype(jnp.float32)) * claim[..., None]
-            dw = jax.lax.psum(jnp.einsum("bd,bkd->bk", gg, h0_part),
+            dw = jax.lax.psum(jnp.einsum("bd,kbd->bk", gg, h0_part),
                               shard_axis).astype(ww.dtype)
-            dlane = ww.astype(jnp.float32)[..., None] * gg[:, None, :]
+            dlane = ww.astype(jnp.float32).T[..., None] * gg[None, :, :]
             dcache = jnp.zeros((rps, tbl.shape[1]), tbl.dtype).at[
                 jnp.maximum(lane_local, 0)].add(
                 jnp.where(own[..., None], dlane, 0.0).astype(tbl.dtype))
@@ -293,12 +290,12 @@ def _fused_bwd(impl, block_d, mesh, shard_axis, local_shard, dynamic, res, g):
                 dcache = jax.lax.psum(dcache, dp)
             # miss lanes are shard-independent: every shard computes the
             # identical (replicated-over-cache-axis) streamed gradient
-            dstreamed = jnp.zeros(st.shape, st.dtype).at[ix].add(
+            dstreamed = jnp.zeros(st.shape, st.dtype).at[ix_t].add(
                 jnp.where(miss[..., None], dlane, 0.0).astype(st.dtype))
             return dcache, dstreamed, dw
 
-        fn = shard_map_compat(
-            body, mesh=mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(P(shard_axis, None), P(bspec, None), P(bspec),
                       P(bspec, None), P(bspec, None), P(bspec, None)),
             out_specs=(P(shard_axis, None), P(bspec, None), P(bspec, None)))
@@ -306,17 +303,18 @@ def _fused_bwd(impl, block_d, mesh, shard_axis, local_shard, dynamic, res, g):
         return dcache, dstreamed, zslots, zidx, dw, zls
 
     g = g.astype(jnp.float32)
-    lane_slots = jnp.take(slots.astype(jnp.int32), idx, axis=0)     # [B, K]
+    idx_t = idx.T                                                   # [K, B]
+    lane_slots = jnp.take(slots.astype(jnp.int32), idx_t, axis=0)
     hit = (lane_slots >= 0)[..., None]
     rows_hit = jnp.take(cache_table, jnp.clip(lane_slots, 0), axis=0)
-    rows_miss = jnp.take(streamed, idx, axis=0)
-    h0 = jnp.where(hit, rows_hit, rows_miss).astype(jnp.float32)    # [B, K, D]
-    dw = jnp.einsum("bd,bkd->bk", g, h0).astype(w.dtype)
-    dlane = w.astype(jnp.float32)[..., None] * g[:, None, :]        # [B, K, D]
+    rows_miss = jnp.take(streamed, idx_t, axis=0)
+    h0 = jnp.where(hit, rows_hit, rows_miss).astype(jnp.float32)    # [K, B, D]
+    dw = jnp.einsum("bd,kbd->bk", g, h0).astype(w.dtype)
+    dlane = w.astype(jnp.float32).T[..., None] * g[None, :, :]      # [K, B, D]
     dcache = jnp.zeros(cache_table.shape, cache_table.dtype).at[
         jnp.clip(lane_slots, 0)].add(
         jnp.where(hit, dlane, 0.0).astype(cache_table.dtype))
-    dstreamed = jnp.zeros(streamed.shape, streamed.dtype).at[idx].add(
+    dstreamed = jnp.zeros(streamed.shape, streamed.dtype).at[idx_t].add(
         jnp.where(hit, 0.0, dlane).astype(streamed.dtype))
     return dcache, dstreamed, zslots, zidx, dw, zls
 
@@ -367,10 +365,7 @@ def cache_lookup_agg(cache_table: jax.Array, streamed: jax.Array,
     mix of home shards without retracing — the DP > 1 regime.  Mutually
     exclusive with ``local_shard`` (the static argument wins).
     """
-    d = cache_table.shape[1]
-    bd = min(block_d, d)
-    while d % bd:
-        bd -= 1
+    bd = block_width(cache_table.shape[1], block_d)
     if mesh is None or shard_axis not in getattr(mesh, "axis_names", ()):
         local_shard = None          # nothing to skip without a cache axis
         local_shards = None

@@ -33,10 +33,16 @@ def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
 
 
 def make_host_mesh(data: int = 1, model: int = 1) -> jax.sharding.Mesh:
-    """Small mesh over however many devices this host exposes (tests)."""
+    """Small mesh over however many devices this host exposes (tests).
+
+    Auto axis types: the engine's steps place operands with explicit
+    NamedShardings and shard_map, so the mesh must not type every array's
+    sharding (Explicit axes make ``jnp.take`` on a row-sharded cache table
+    refuse to pick an output sharding)."""
     n = len(jax.devices())
     assert data * model <= n, (data, model, n)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def cache_shard_axis(mesh: jax.sharding.Mesh) -> str:

@@ -33,40 +33,6 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(body, *, mesh, in_specs, out_specs):
-    """Call shard_map across jax version bands: 0.4.x ships it under
-    jax.experimental with check_rep; newer jax exposes jax.shard_map whose
-    replication-check kwarg migrated check_rep -> check_vma.  Dispatch on
-    the actual signature, not the version."""
-    import inspect
-
-    if hasattr(jax, "shard_map"):
-        fn = jax.shard_map
-    else:
-        from jax.experimental.shard_map import shard_map as fn
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):          # C-level / wrapped callable
-        params = None
-    if params is not None:
-        if "check_vma" in params:
-            kw = {"check_vma": False}
-        elif "check_rep" in params:
-            kw = {"check_rep": False}
-        else:
-            kw = {}
-        return fn(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    # unreadable signature: still try to DISABLE the replication check (the
-    # bodies here rely on it being off) before falling back to defaults
-    for kw in ({"check_vma": False}, {"check_rep": False}, {}):
-        try:
-            return fn(body, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, **kw)
-        except TypeError:
-            if not kw:
-                raise
-    raise AssertionError("unreachable")
-
 _LOGICAL_TO_PHYSICAL = {
     "batch": ("pod", "data"),
     "model": ("model",),

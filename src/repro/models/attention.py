@@ -20,8 +20,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.kernels import ref as kref
-from repro.launch.sharding import (axis_size, constrain, constrain_hard,
-                                   shard_map_compat)
+from repro.launch.sharding import axis_size, constrain, constrain_hard
 from repro.models.common import apply_rope, dense_init, rms_norm
 
 
@@ -157,7 +156,7 @@ def sharded_attention(q, k, v, *, causal, window, impl,
         return _attend(q, k, v, causal=causal, window=window, impl=impl,
                        q_pos=q_pos, kv_pos=kv_pos)
 
-    fn = shard_map_compat(body, mesh=mesh, **io)
+    fn = jax.shard_map(body, mesh=mesh, check_vma=False, **io)
     return fn(q, k, v, q_pos, kv_pos)
 
 

@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoECfg
-from repro.launch.sharding import constrain, shard_map_compat
+from repro.launch.sharding import constrain
 from repro.models.common import activation, dense_init
 from repro.models.ffn import ffn_forward, init_ffn
 
@@ -145,8 +145,8 @@ def moe_forward(p: dict, cfg: ArchConfig, x: jnp.ndarray) -> jnp.ndarray:
                                   expert_offset=rank * e_loc)
             return jax.lax.psum(out, "model")   # combine top-k expert outputs
 
-        out = shard_map_compat(
-            body, mesh=mesh,
+        out = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(P(dp, None), P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),
             out_specs=P(dp, None),

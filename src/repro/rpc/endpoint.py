@@ -383,6 +383,8 @@ def main(argv=None) -> int:
 
     from repro.gns.config import EngineConfig
     from repro.gns.engine import GNSEngine
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     with open(args.config) as f:
         cfg = EngineConfig.from_dict(json.load(f))
     engine = GNSEngine(cfg)

@@ -17,9 +17,8 @@ generation's :class:`~repro.sampling.adjacency.DeviceCacheAdj`:
   to the host sampler's (per-lane marginals match; the joint differs by
   with- vs without-replacement, a documented approximation whose modulo
   bias is < n_c/2³² and whose unbiasedness is property-tested).
-* :func:`slot_gather_agg_pallas` — the Pallas gather-aggregate over the
-  drawn table rows (one launch; scalar-prefetched lane rows drive the
-  BlockSpec index map exactly like ``kernels/cache_lookup.py``).
+* :func:`slot_gather_agg_pallas` — the Pallas gather-aggregate
+  (``kernels/gather_agg.py``) over the drawn table rows, dead lanes masked.
 * :func:`gns_sample_agg` — the jitted entry the model's layer 0 calls:
   draw, merge host-fallback lanes (destination rows NOT in the cache are
   sampled by the host — ``top-up misses fall back to the host path``), and
@@ -41,9 +40,8 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.gather_agg import gather_agg_pallas
 from repro.sampling.adjacency import DeviceCacheAdj
 from repro.sampling.ref import slot_gather_agg_ref
 from repro.sampling.rng import mix32
@@ -84,98 +82,60 @@ def draw_lanes(adj: DeviceCacheAdj, dst_rows: jax.Array, keys: jax.Array,
     start = jnp.take(adj.indptr, rowc)
     n_c = jnp.take(adj.indptr, rowc + 1) - start              # int32 [B]
 
+    # lanes are built k-major ([k, B]) and transposed on return: gathers
+    # with a narrow minor index dim cost the TPU compiler minutes at the
+    # paper's 176k destination rows (see kernels/gather_agg.py)
     key_lo = jnp.repeat(keys[:, 0], pad, total_repeat_length=B)
     key_hi = jnp.repeat(keys[:, 1], pad, total_repeat_length=B)
     local = jnp.arange(B, dtype=jnp.uint32) % jnp.uint32(max(pad, 1))
-    lane = jnp.arange(k, dtype=jnp.uint32)
-    bits = mix32(key_lo[:, None], key_hi[:, None],
-                 local[:, None], lane[None, :])               # [B, k] u32
+    lane = jnp.arange(k, dtype=jnp.uint32)[:, None]
+    bits = mix32(key_lo[None, :], key_hi[None, :],
+                 local[None, :], lane)                        # [k, B] u32
 
-    take_all = (n_c <= k)[:, None]
+    take_all = (n_c <= k)[None, :]
     ncs = jnp.maximum(n_c, 1)
-    off_draw = (bits % ncs[:, None].astype(jnp.uint32)).astype(jnp.int32)
-    off_seq = jnp.minimum(lane.astype(jnp.int32)[None, :],
-                          jnp.maximum(n_c - 1, 0)[:, None])
+    off_draw = (bits % ncs[None, :].astype(jnp.uint32)).astype(jnp.int32)
+    off_seq = jnp.minimum(lane.astype(jnp.int32),
+                          jnp.maximum(n_c - 1, 0)[None, :])
     off = jnp.where(take_all, off_seq, off_draw)
-    flat = jnp.clip(start[:, None] + off, 0, adj.indices.shape[0] - 1)
-    rows = jnp.take(adj.indices, flat)                        # [B, k]
+    flat = jnp.clip(start[None, :] + off, 0, adj.indices.shape[0] - 1)
+    rows = jnp.take(adj.indices, flat)                        # [k, B]
 
-    alive = ((dst >= 0) & (n_c > 0))[:, None]
+    alive = ((dst >= 0) & (n_c > 0))[None, :]
     alive = alive & jnp.where(
-        take_all, lane.astype(jnp.int32)[None, :] < n_c[:, None], True)
+        take_all, lane.astype(jnp.int32) < n_c[None, :], True)
 
     # the exact host weight: coeff = p^C_u * min(k, n_c)/n_c (clamped),
     # w = 1/(coeff * max(deg, 1))  — importance.importance_coefficients
     # with the hit probabilities precomputed per row at build time
-    ncf = jnp.maximum(n_c.astype(jnp.float32), 1.0)[:, None]
+    ncf = jnp.maximum(n_c.astype(jnp.float32), 1.0)[None, :]
     hitp = jnp.take(adj.hitp, jnp.clip(rows, 0))
     coeff = jnp.maximum(hitp * (jnp.minimum(float(k), ncf) / ncf), 1e-6)
-    deg = jnp.maximum(jnp.take(adj.deg, rowc), 1.0)[:, None]
+    deg = jnp.maximum(jnp.take(adj.deg, rowc), 1.0)[None, :]
     w = jnp.where(alive, 1.0 / (coeff * deg), 0.0)
     rows = jnp.where(alive, rows, -1)
-    return rows, w
+    return rows.T, w.T
 
 
 # ---------------------------------------------------------------------------
 # Pallas gather-aggregate over drawn table rows
 # ---------------------------------------------------------------------------
 
-def _kernel(lane_ref, w_ref, cache_ref, out_ref):
-    b = pl.program_id(0)
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    # dead lanes were pre-masked to w == 0 and row 0 XLA-side, so the DMA'd
-    # tile is discarded by the multiply; accumulation order is fixed
-    # (K innermost, ascending) and matches slot_gather_agg_ref bitwise for
-    # exactly-representable products (see kernels/cache_lookup.py)
-    out_ref[...] += w_ref[b, k] * cache_ref[...].astype(out_ref.dtype)
-
-
 def slot_gather_agg_pallas(cache_table: jax.Array, lane_rows: jax.Array,
                            w: jax.Array, block_d: int = 2048,
                            interpret: bool = False) -> jax.Array:
     """out[b] = Σ_k w[b,k] · cache_table[lane_rows[b,k]]  ([B, D] f32).
 
-    ``lane_rows`` rides scalar prefetch (SMEM) and drives the cache-row
-    BlockSpec index map; per grid step the pipeline DMAs one (1, block_d)
-    tile at row ``max(lane_rows[b,k], 0)``.  Grid (B, D/block_d, K) with K
-    innermost keeps the output tile VMEM-resident across the accumulation.
+    The row gather of ``kernels/gather_agg.py`` with dead lanes
+    (``lane_rows < 0``) masked XLA-side: their weight is 0 and their row is
+    clamped to 0, so the DMA'd tile is discarded by the multiply.  The
+    accumulation order (K innermost, ascending) matches
+    ``slot_gather_agg_ref`` bitwise for exactly-representable products.
     """
-    _, d = cache_table.shape
-    bsz, num_k = lane_rows.shape
-    block_d = min(block_d, d)
-    while d % block_d:                 # largest divisor <= requested block
-        block_d -= 1
-    grid = (bsz, d // block_d, num_k)
-
     lr = lane_rows.astype(jnp.int32)
     w_eff = jnp.where(lr >= 0, w.astype(jnp.float32), 0.0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                   # lane rows ride in SMEM
-        grid=grid,
-        in_specs=[
-            # weights: full (B, K) in VMEM — tiny (4·B·K bytes)
-            pl.BlockSpec((bsz, num_k), lambda b, db, k, lane_ref: (0, 0)),
-            # cache rows: the drawn table row (clamped for dead lanes)
-            pl.BlockSpec((1, block_d),
-                         lambda b, db, k, lane_ref:
-                         (jnp.maximum(lane_ref[b, k], 0), db)),
-        ],
-        out_specs=pl.BlockSpec((1, block_d),
-                               lambda b, db, k, lane_ref: (b, db)),
-    )
-    fn = pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz, d), jnp.float32),
-        interpret=interpret,
-    )
-    return fn(lr, w_eff, cache_table)
+    return gather_agg_pallas(cache_table, jnp.maximum(lr, 0), w_eff,
+                             block_d=block_d, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -215,7 +175,6 @@ def gns_sample_agg(adj: DeviceCacheAdj, cache_table: jax.Array,
 
         from repro.kernels.cache_lookup import shard_slot_map
         from repro.kernels.ops import _dp_spec
-        from repro.launch.sharding import shard_map_compat
 
         n = mesh.shape[shard_axis]
         rows_tot = cache_table.shape[0]
@@ -240,8 +199,8 @@ def gns_sample_agg(adj: DeviceCacheAdj, cache_table: jax.Array,
                                               interpret=_interpret())
             return jax.lax.psum(part, shard_axis)
 
-        fn = shard_map_compat(
-            body, mesh=mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh, check_vma=False,
             in_specs=(P(shard_axis, None), P(bspec, None), P(bspec, None)),
             out_specs=P(bspec, None))
         return fn(cache_table, lane_rows, lane_w)

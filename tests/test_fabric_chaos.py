@@ -24,6 +24,7 @@ from __future__ import annotations
 import os
 import subprocess
 import sys
+import threading
 import time
 
 import numpy as np
@@ -164,8 +165,21 @@ def test_inflight_results_bitwise_identical_across_swap(tiny_ds):
             for i, ids in enumerate(chunks):
                 if swap_under_last and i == len(chunks) - 1:
                     w0.stall_s = 1.5
+                    # a worker lists a batch in flight before it samples
+                    # it: wait for the sample itself, which pins the
+                    # generation, before swapping
+                    sampled = threading.Event()
+                    prepare = fab._prepare
+
+                    def _prepare(*args, **kw):
+                        mb = prepare(*args, **kw)
+                        sampled.set()
+                        return mb
+
+                    fab._prepare = _prepare
                     fut = fab.submit(ids, worker=0)
-                    assert _wait(lambda: len(w0._inflight) > 0)
+                    assert sampled.wait(timeout=60)
+                    assert len(w0._inflight) > 0
                     # the batch is sampled and pinned; swap the live
                     # generation UNDER it
                     v0 = eng.store.version

@@ -81,6 +81,39 @@ def test_gather_agg_ops_wrapper_dispatch():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kernel", ["gather_agg", "slot_gather",
+                                    "cache_lookup"])
+def test_lane_chunks_bitwise_parity(kernel, monkeypatch):
+    """SMEM-sized lane chunks (several pallas_calls under lax.map, k-major
+    lanes, a padded tail chunk) must not change a single bit."""
+    from repro.kernels import gather_agg as ga
+    from repro.kernels.cache_lookup import cache_lookup_agg_pallas
+    from repro.sampling.kernels import slot_gather_agg_pallas
+    from repro.sampling.ref import slot_gather_agg_ref
+
+    monkeypatch.setattr(ga, "SMEM_LANES", 12)     # 3 rows x K=4 per chunk
+    assert ga.lane_chunks(11, 4) == (3, 4)
+    rng = np.random.default_rng(0)
+    feat = jnp.asarray(rng.integers(-8, 9, (40, 16)), jnp.float32)
+    idx = jnp.asarray(rng.integers(0, 40, (11, 4)), jnp.int32)
+    w = jnp.asarray(rng.integers(-4, 5, (11, 4)), jnp.float32)
+    if kernel == "gather_agg":
+        out = gather_agg_pallas(feat, idx, w, interpret=True)
+        expect = ref.gather_agg_ref(feat, idx, w)
+    elif kernel == "slot_gather":
+        lanes = jnp.where(idx % 3 == 0, -1, idx)         # some dead lanes
+        out = slot_gather_agg_pallas(feat, lanes, w, interpret=True)
+        expect = slot_gather_agg_ref(feat, lanes, w)
+    else:
+        streamed = jnp.asarray(rng.integers(-8, 9, (40, 16)), jnp.float32)
+        slots = jnp.asarray(np.where(np.arange(40) % 2 == 0,
+                                     np.arange(40) % 17, -1), jnp.int32)
+        out = cache_lookup_agg_pallas(feat[:17], streamed, slots, idx, w,
+                                      interpret=True)
+        expect = ref.cache_lookup_agg_ref(feat[:17], streamed, slots, idx, w)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+
+
 # ---------------------------------------------------------------------------
 # flash_attention
 # ---------------------------------------------------------------------------

@@ -194,15 +194,20 @@ def test_send_recv_over_socketpair():
 
         t = threading.Thread(target=pump)
         t.start()
-        for i, (kind, meta, arrays) in enumerate(frames):
+        received = []
+        for kind, meta, arrays in frames:
             k, m, arr, n = recv_frame(b)
             assert (k, m) == (kind, meta)
             for name in arrays:
                 np.testing.assert_array_equal(arr[name], arrays[name])
-            assert n == sent[i]
+            received.append(n)
         with pytest.raises(ChannelClosed):   # boundary EOF: clean close
             recv_frame(b)
-        t.join()
+        t.join(timeout=30)
+        assert not t.is_alive()
+        # compared once the sender is done: a frame can arrive before the
+        # sender has recorded its size
+        assert received == sent
     finally:
         a.close()
         b.close()
