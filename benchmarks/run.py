@@ -4,7 +4,6 @@
   Table 4  bench_input_nodes       #input nodes per batch NS vs GNS
   Table 5  bench_isolated          LADIES isolated-node pathology
   Table 6  bench_cache_sensitivity GNS cache size x refresh period
-  Fig 1/2  bench_breakdown         runtime breakdown + byte ledger
   Fig 3    bench_convergence       F1 vs epoch, 4 samplers
   §Roofline bench_roofline         aggregates dry-run JSONs (no compute)
   Serving  bench_serve             micro-batched GNSServer vs infer() loop
@@ -31,7 +30,7 @@ def main(argv=None) -> None:
     from repro.launch.compile_cache import use_compile_cache
     use_compile_cache()
 
-    from benchmarks import (bench_breakdown, bench_cache_sensitivity,
+    from benchmarks import (bench_cache_sensitivity,
                             bench_convergence, bench_fabric,
                             bench_input_nodes, bench_isolated,
                             bench_roofline, bench_rpc, bench_serve,
@@ -41,7 +40,6 @@ def main(argv=None) -> None:
         "input_nodes": bench_input_nodes.run,
         "isolated": bench_isolated.run,
         "cache_sensitivity": bench_cache_sensitivity.run,
-        "breakdown": bench_breakdown.run,
         "convergence": bench_convergence.run,
         "roofline": bench_roofline.run,
         "serve": bench_serve.run,

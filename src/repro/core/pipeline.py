@@ -24,13 +24,13 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Iterator, Optional
 
 import numpy as np
 
 from repro.analysis import guarded_by
 from repro.core.minibatch import MiniBatch
+from repro.featurestore.meter import PIPELINE_WAIT, Span
 
 
 class EpochLoader:
@@ -119,11 +119,12 @@ class Prefetcher:
     ``wait_s`` accumulates the consumer's time blocked on the queue — the
     *sampler-stall* metric (ROADMAP item 2): when the host sampler is the
     bottleneck the consumer idles here instead of stepping the device.
-    With ``meter`` set, the same time lands on
-    ``TrafficMeter.t_prefetch_wait`` so the benchmark breakdown reports it.
+    Each wait is a ``repro.pipeline.wait`` span, booked on ``meter`` when
+    one is given (``TrafficMeter.t_prefetch_wait`` reads its total).
     """
 
     _SENTINEL = object()
+    _EMPTY = object()
 
     def __init__(self, it: Iterator[MiniBatch], depth: int = 2,
                  timeout_s: Optional[float] = None, meter=None):
@@ -151,29 +152,28 @@ class Prefetcher:
         finally:
             self._q.put(self._SENTINEL)
 
-    def _note_wait(self, dt: float):
-        self.wait_s += dt
-        if self._meter is not None:
-            self._meter.t_prefetch_wait += dt
+    def _take(self, timeout: Optional[float] = None):
+        """One blocking take from the queue (``_EMPTY`` on timeout), timed
+        as a wait."""
+        with Span(self._meter, PIPELINE_WAIT) as sp:
+            try:
+                item = self._q.get(timeout=timeout)
+            except queue.Empty:
+                item = self._EMPTY
+        self.wait_s += sp.wall_s
+        return item
 
     def __iter__(self):
         while True:
-            t0 = time.perf_counter()
-            try:
-                item = self._q.get(timeout=self._timeout)
-            except queue.Empty:
-                self._note_wait(time.perf_counter() - t0)
+            item = self._take(self._timeout)
+            if item is self._EMPTY:
                 # straggler: reuse the last batch instead of stalling the step
                 if self._last is None:
-                    t1 = time.perf_counter()
-                    item = self._q.get()      # nothing to reuse yet: block
-                    self._note_wait(time.perf_counter() - t1)
+                    item = self._take()       # nothing to reuse yet: block
                 else:
                     self.reused += 1
                     yield self._last
                     continue
-            else:
-                self._note_wait(time.perf_counter() - t0)
             if item is self._SENTINEL:
                 if self._err is not None:
                     raise self._err

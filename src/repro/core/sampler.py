@@ -373,6 +373,13 @@ class GNSSampler:
         return c_nbrs, c_mask, w
 
     def sample(self, targets: np.ndarray, rng: np.random.Generator) -> MiniBatch:
+        """One minibatch for ``targets``, timed as a ``repro.sample`` span
+        on the store's accounting meter."""
+        with self.store.span("repro.sample"):
+            return self._sample(targets, rng)
+
+    def _sample(self, targets: np.ndarray,
+                rng: np.random.Generator) -> MiniBatch:
         assert self.cache is not None, "call start_epoch/refresh_cache first"
         cfg = self.cfg
         ids = np.asarray(targets, dtype=np.int64)

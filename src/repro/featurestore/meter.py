@@ -17,13 +17,89 @@ cache hit as *local* or *remote* depending on whether the row's shard is the
 requesting group's home shard (``lanes_local`` / ``lanes_remote`` /
 ``local_hit_fraction``) — the cross-shard lookup traffic the locality-aware
 placement minimizes.
+
+Spans: the meter's one timing mechanism.  ``with meter.span(name):`` adds
+the interval's wall seconds and its thread's CPU seconds to a per-name
+accumulator (``span_stats``), and opens a ``jax.profiler.TraceAnnotation``
+of the same name, so that under a profiler every span sits on the device
+trace's clock.  The training path's spans:
+
+* ``repro.train.fit``      — ``GNSEngine.fit``'s epoch loop (main thread);
+* ``repro.pipeline.wait``  — the step loop blocked on the prefetch queue;
+* ``repro.sample``         — one ``GNSSampler.sample`` call (prefetch thread);
+* ``repro.sample.slice``   — the host feature slice in ``assemble_input``;
+* ``repro.train.put``      — the ``device_put`` call, which returns at enqueue;
+* ``repro.train.h2d``      — from the enqueue until every leaf of the copy is
+  ready on the device, booked by :class:`LandingFence`'s thread;
+* ``repro.train.dispatch`` — the call into the jitted step, to its return;
+* ``repro.train.sync``     — the loss readback that waits for the step.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional
+import queue
+import threading
+import time
+import traceback
+import weakref
+from typing import Callable, Dict, Optional
 
+import jax
 import numpy as np
+
+from repro.analysis import guarded_by
+
+PIPELINE_WAIT = "repro.pipeline.wait"
+H2D = "repro.train.h2d"
+
+
+def tree_nbytes(tree) -> int:
+    """Bytes of every array leaf of a pytree, from shapes: what one
+    host-to-device copy of it ships."""
+    return int(sum(x.nbytes for x in jax.tree_util.tree_leaves(tree)))
+
+
+@dataclasses.dataclass
+class SpanStats:
+    """What one span name has accumulated."""
+    count: int = 0
+    wall_s: float = 0.0
+    cpu_s: float = 0.0             # CPU time of the thread inside the span
+
+    def as_dict(self) -> dict:
+        return {"count": self.count, "wall_s": round(self.wall_s, 6),
+                "cpu_s": round(self.cpu_s, 6)}
+
+
+class Span:
+    """One timed interval (module docstring), booked on ``meter`` at exit
+    (``meter=None`` times and annotates but books nowhere).
+
+    ``t0`` backdates the wall clock's start to an earlier
+    ``time.perf_counter()`` reading, for an interval that began on another
+    thread; the trace annotation and the CPU clock start at entry.  After
+    exit, ``wall_s`` and ``cpu_s`` hold the interval.
+    """
+    __slots__ = ("meter", "name", "t0", "wall_s", "cpu_s", "_w0", "_c0",
+                 "_ann")
+
+    def __init__(self, meter: Optional["TrafficMeter"], name: str,
+                 t0: Optional[float] = None):
+        self.meter, self.name, self.t0 = meter, name, t0
+
+    def __enter__(self) -> "Span":
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
+        self._w0 = time.perf_counter() if self.t0 is None else self.t0
+        self._c0 = time.thread_time()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.wall_s = time.perf_counter() - self._w0
+        self.cpu_s = time.thread_time() - self._c0
+        self._ann.__exit__(*exc)
+        if self.meter is not None:
+            self.meter.book(self.name, self.wall_s, self.cpu_s)
 
 
 @dataclasses.dataclass
@@ -45,10 +121,16 @@ class TierStats:
                 "hit_rate": round(self.hit_rate, 4)}
 
 
+@guarded_by("_span_lock", "_spans")
 @dataclasses.dataclass
 class TrafficMeter:
-    """Aggregate host↔device + host-memory traffic counters (bytes / seconds)."""
+    """Aggregate host↔device + host-memory traffic counters (bytes) and
+    spans (seconds; module docstring).  The prefetch and fence threads book
+    spans beside the main thread, so the span accumulator takes a lock."""
     bytes_streamed: int = 0        # host -> device feature rows (PCIe analog)
+    bytes_h2d: int = 0             # every leaf handed to device_put by
+                                   # GNSEngine._put_batch, from shapes:
+                                   # what was shipped, padding included
     bytes_sliced: int = 0          # host-memory gather (CPU bandwidth, step 2)
     bytes_cache_fill: int = 0      # cache refresh host-side gather (|C| rows)
     bytes_cache_upload: int = 0    # cache refresh host->device transfer: sum of
@@ -78,19 +160,46 @@ class TrafficMeter:
                                    # (cross-shard traffic the placement
                                    # solver exists to remove)
     bytes_cross_shard: int = 0     # remote-hit rows x row bytes
-    t_sample: float = 0.0
-    t_slice: float = 0.0
-    t_copy: float = 0.0
-    t_compute: float = 0.0
     t_refresh: float = 0.0         # background cache-generation build time
-    t_prefetch_wait: float = 0.0   # consumer time blocked on the prefetch
-                                   # queue (sampler-stall; ROADMAP item 2's
-                                   # success metric — device-backend sampling
-                                   # exists to drive this to ~0)
     steps: int = 0
     tiers: Dict[str, TierStats] = dataclasses.field(default_factory=dict)
     group_hist: Dict[int, np.ndarray] = dataclasses.field(default_factory=dict)
                                    # DP group -> per-node request counts
+    _spans: Dict[str, SpanStats] = dataclasses.field(
+        default_factory=dict, init=False, repr=False, compare=False)
+    _span_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, init=False, repr=False,
+        compare=False)
+
+    def span(self, name: str) -> Span:
+        """``with meter.span(name):`` times and books one interval."""
+        return Span(self, name)
+
+    def book(self, name: str, wall_s: float, cpu_s: float) -> None:
+        """Add one interval to ``name``'s accumulator (any thread)."""
+        with self._span_lock:
+            st = self._spans.get(name)
+            if st is None:
+                st = self._spans[name] = SpanStats()
+            st.count += 1
+            st.wall_s += wall_s
+            st.cpu_s += cpu_s
+
+    def span_stats(self, name: str) -> SpanStats:
+        """A copy of ``name``'s accumulator (zeros before its first span)."""
+        with self._span_lock:
+            return dataclasses.replace(self._spans.get(name, SpanStats()))
+
+    def span_totals(self) -> Dict[str, SpanStats]:
+        """A copy of every span name's accumulator."""
+        with self._span_lock:
+            return {k: dataclasses.replace(v) for k, v in self._spans.items()}
+
+    @property
+    def t_prefetch_wait(self) -> float:
+        """Seconds the step loop waited on the prefetch queue (the sampler
+        stall; device-backend sampling exists to drive it to ~0)."""
+        return self.span_stats(PIPELINE_WAIT).wall_s
 
     def tier(self, name: str) -> TierStats:
         """Per-tier counters, created on first touch."""
@@ -152,16 +261,13 @@ class TrafficMeter:
         self.steps += 1
 
     def breakdown(self) -> dict:
-        total = self.t_sample + self.t_slice + self.t_copy + self.t_compute
         out = {
-            "sample_s": round(self.t_sample, 4),
-            "slice_s": round(self.t_slice, 4),
-            "copy_s": round(self.t_copy, 4),
-            "compute_s": round(self.t_compute, 4),
-            "total_s": round(total, 4),
             "refresh_s": round(self.t_refresh, 4),
             "prefetch_wait_s": round(self.t_prefetch_wait, 4),
+            "spans": {k: v.as_dict()
+                      for k, v in sorted(self.span_totals().items())},
             "bytes_streamed": self.bytes_streamed,
+            "bytes_h2d": self.bytes_h2d,
             "bytes_cache_fill": self.bytes_cache_fill,
             "bytes_cache_upload": self.bytes_cache_upload,
             "bytes_adj_upload": self.bytes_adj_upload,
@@ -178,3 +284,53 @@ class TrafficMeter:
         if self.tiers:
             out["tiers"] = {k: v.as_dict() for k, v in self.tiers.items()}
         return out
+
+
+class LandingFence:
+    """Books when host-to-device copies land, off the caller's thread.
+
+    ``device_put`` returns at enqueue.  :meth:`land` hands its result, the
+    enqueue time and a meter to one long-lived daemon thread, which waits
+    until every leaf is ready on the device (``wait``) and books the
+    interval from the enqueue as a ``repro.train.h2d`` span.  The caller
+    never waits, so the step loop keeps its schedule.  The copies must not
+    be donated to a step, since the thread reads them.
+    """
+
+    def __init__(self, wait: Callable = jax.block_until_ready):
+        self._q: queue.SimpleQueue = queue.SimpleQueue()
+        threading.Thread(target=_fence_loop, args=(self._q, wait),
+                         daemon=True, name="h2d-fence").start()
+        # the thread holds the queue, never the fence: it stops once the
+        # fence is collected
+        weakref.finalize(self, self._q.put, None)
+
+    def land(self, tree, t0: float, meter: TrafficMeter) -> None:
+        """Book ``tree``'s landing on ``meter``, timed from ``t0``."""
+        self._q.put((tree, t0, meter))
+
+    def flush(self, timeout: Optional[float] = None) -> bool:
+        """Wait until every copy handed over before the call is booked."""
+        done = threading.Event()
+        self._q.put(done)
+        return done.wait(timeout)
+
+
+def _fence_loop(q: queue.SimpleQueue, wait: Callable) -> None:
+    while True:
+        item = q.get()
+        if item is None:
+            return
+        if isinstance(item, threading.Event):
+            item.set()
+            continue
+        tree, t0, meter = item
+        del item                     # hold no device batch while idle
+        try:
+            with Span(meter, H2D, t0=t0):
+                wait(tree)
+        except Exception:
+            # a failed copy also fails the step that reads it; report it
+            # and keep the fence alive for the copies after it
+            traceback.print_exc()
+        del tree, meter

@@ -58,7 +58,7 @@ from typing import Optional, Sequence
 import numpy as np
 
 from repro.analysis import guarded_by
-from repro.featurestore.meter import TrafficMeter
+from repro.featurestore.meter import Span, TrafficMeter
 from repro.featurestore.placement import (PlacementMap, RoutingTable,
                                           home_shard, identity_placement,
                                           routing_table_from_state,
@@ -446,6 +446,12 @@ class FeatureStore:
         finally:
             self.record, self.serve_meter = prev_record, prev_meter
 
+    def span(self, name: str) -> Span:
+        """A :class:`~repro.featurestore.meter.Span` booked on this mode's
+        accounting sink: the training meter, the ``serving()`` meter, or
+        nothing (evaluation)."""
+        return Span(self.meter if self.record else self.serve_meter, name)
+
     # ------------------------------------------------------------------
     # tier reads
     # ------------------------------------------------------------------
@@ -476,21 +482,20 @@ class FeatureStore:
         valid[:n_in] = True
         miss = (slots < 0) & valid
         hits = int(((slots >= 0) & valid).sum())
-        t0 = time.perf_counter()
-        streamed = np.zeros((len(ids_p), self.feat_dim), np.float32)
-        miss_ids = ids_p[miss]
-        if len(miss_ids):
-            streamed[miss] = self.features[miss_ids]
+        # accounting sink for this mode: the training meter, the serving
+        # meter (``serving()`` scope), or nothing (evaluation)
+        meter = self.meter if self.record else self.serve_meter
+        with Span(meter, "repro.sample.slice"):
+            streamed = np.zeros((len(ids_p), self.feat_dim), np.float32)
+            miss_ids = ids_p[miss]
+            if len(miss_ids):
+                streamed[miss] = self.features[miss_ids]
         # locality: which shard serves each hit, vs the group's home shard
         home = home_shard(group, state.n_shards)
         hit_shards = slots[(slots >= 0) & valid] // state.rows_per_shard
         n_local = int((hit_shards == home).sum())
         all_local = state.n_shards > 1 and n_local == len(hit_shards)
-        # accounting sink for this mode: the training meter, the serving
-        # meter (``serving()`` scope), or nothing (evaluation)
-        meter = self.meter if self.record else self.serve_meter
         if meter is not None:
-            meter.t_slice += time.perf_counter() - t0
             dev = meter.tier("device")
             dev.hits += hits
             dev.misses += len(miss_ids)

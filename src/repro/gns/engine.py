@@ -41,6 +41,7 @@ from repro.core.minibatch import DeviceBatch, LayerBlock, MiniBatch
 from repro.core.pipeline import EpochLoader, Prefetcher
 from repro.core.sampler import GNSSampler, make_sampler
 from repro.featurestore import FeatureStore, TrafficMeter
+from repro.featurestore.meter import LandingFence, tree_nbytes
 from repro.gns.config import EngineConfig
 from repro.kernels.ops import dp_group_count
 from repro.launch import sharding as shlib
@@ -71,10 +72,11 @@ def make_train_step(mcfg: graphsage.SageConfig, opt: AdamW):
     """
     def train_step(params, opt_state, batch, cache_table, home_shards,
                    device_adj=None):
-        (loss, acc), grads = jax.value_and_grad(
-            graphsage.loss_fn, has_aux=True)(params, batch, cache_table,
-                                             mcfg, home_shards, device_adj)
-        params, opt_state = opt.update(grads, opt_state, params)
+        with jax.named_scope("gns_train_step"):
+            (loss, acc), grads = jax.value_and_grad(
+                graphsage.loss_fn, has_aux=True)(params, batch, cache_table,
+                                                 mcfg, home_shards, device_adj)
+            params, opt_state = opt.update(grads, opt_state, params)
         return params, opt_state, loss, acc
     return train_step
 
@@ -191,6 +193,8 @@ class GNSEngine:
         # training breakdown the paper's tables are built from
         self.meter_eval = TrafficMeter()
         self.meter_infer = TrafficMeter()
+        # books when each copy lands on the device, off the caller's thread
+        self.fence = LandingFence()
         if cfg.sampler == "gns":
             # the facade owns all three feature tiers + the refresh lifecycle
             self.store = FeatureStore(
@@ -282,15 +286,19 @@ class GNSEngine:
     def _put_batch(self, host_batch, meter: Optional[TrafficMeter] = None):
         """Host->device transfer with paired accounting.
 
-        Every engine transfer funnels through here so each copy's wall
-        time books to exactly one :class:`TrafficMeter` — training by
-        default, the eval/infer side meters or a serving meter when passed.
-        The meterlint pass enforces the pairing repo-wide (error tier).
+        Every engine transfer funnels through here so each copy books to
+        exactly one :class:`TrafficMeter` — training by default, the
+        eval/infer side meters or a serving meter when passed: its bytes
+        (``bytes_h2d``), the enqueue (``repro.train.put``) and, through
+        :attr:`fence`, its landing (``repro.train.h2d``).  The meterlint
+        pass enforces the pairing repo-wide (error tier).
         """
         m = meter if meter is not None else self.meter
+        m.bytes_h2d += tree_nbytes(host_batch)
         t0 = time.perf_counter()
-        out = jax.device_put(host_batch)
-        m.t_copy += time.perf_counter() - t0
+        with m.span("repro.train.put"):
+            out = jax.device_put(host_batch)
+        self.fence.land(out, t0, m)
         return out
 
     @staticmethod
@@ -321,15 +329,14 @@ class GNSEngine:
         m = self.meter
         dev_batch = self._put_batch(mb.device)
         m.add_batch(mb.bytes_streamed)
-        t0 = time.perf_counter()
-        with shlib.use_mesh(self.mesh):     # no-op scope when mesh is None
+        # no-op mesh scope when mesh is None
+        with m.span("repro.train.dispatch"), shlib.use_mesh(self.mesh):
             self.params, self.opt_state, loss, acc = self._train_step(
                 self.params, self.opt_state, dev_batch, self._cache_table(mb),
                 jax.numpy.asarray(home_shards, jax.numpy.int32),
                 self._device_adj(mb))
-        loss = float(loss)
-        m.t_compute += time.perf_counter() - t0
-        return loss, float(acc)
+        with m.span("repro.train.sync"):
+            return float(loss), float(acc)
 
     # ------------------------------------------------------------------
     def fit(self, epochs: int, max_batches: Optional[int] = None,
@@ -348,34 +355,33 @@ class GNSEngine:
         report = TrainReport([], [], [], self.meter)
         n_inputs, n_cached, n_iso, n_b = 0, 0, 0, 0
         fused = self._collate_fused
-        for ep in range(epochs):
-            t_ep = time.perf_counter()
-            # epoch start (cache refresh happens in sampler.start_epoch)
-            it = loader.epoch(ep)
-            if prefetch:
-                it = Prefetcher(it, depth=2, meter=self.meter)
-            else:
-                it = self._timed(it)
-            ep_losses = []
-            group_buf: list = []
-            for mb in it:
-                group_buf.append(mb)
-                if len(group_buf) < G:
-                    continue
-                step_mb, home = collate_groups(group_buf, fused)
-                group_buf = []
-                loss, _ = self.run_batch(step_mb, home)
-                ep_losses.append(loss)
-                n_inputs += step_mb.num_input
-                n_cached += step_mb.num_cached
-                n_iso += step_mb.num_isolated
-                n_b += 1
-            report.epoch_times.append(time.perf_counter() - t_ep)
-            report.losses.append(float(np.mean(ep_losses)) if ep_losses
-                                 else float("nan"))
-            if eval_every and (ep + 1) % eval_every == 0:
-                report.val_acc.append(
-                    self.evaluate(self.ds.val_idx, eval_batches))
+        with self.meter.span("repro.train.fit"):
+            for ep in range(epochs):
+                t_ep = time.perf_counter()
+                # epoch start (cache refresh happens in sampler.start_epoch)
+                it = loader.epoch(ep)
+                if prefetch:
+                    it = Prefetcher(it, depth=2, meter=self.meter)
+                ep_losses = []
+                group_buf: list = []
+                for mb in it:
+                    group_buf.append(mb)
+                    if len(group_buf) < G:
+                        continue
+                    step_mb, home = collate_groups(group_buf, fused)
+                    group_buf = []
+                    loss, _ = self.run_batch(step_mb, home)
+                    ep_losses.append(loss)
+                    n_inputs += step_mb.num_input
+                    n_cached += step_mb.num_cached
+                    n_iso += step_mb.num_isolated
+                    n_b += 1
+                report.epoch_times.append(time.perf_counter() - t_ep)
+                report.losses.append(float(np.mean(ep_losses)) if ep_losses
+                                     else float("nan"))
+                if eval_every and (ep + 1) % eval_every == 0:
+                    report.val_acc.append(
+                        self.evaluate(self.ds.val_idx, eval_batches))
         if n_b:
             # per MINIBATCH, not per step: a DP>1 step consumes G of them,
             # and the paper's Table 3/4 comparisons are per-minibatch
@@ -384,30 +390,6 @@ class GNSEngine:
             report.cached_nodes_per_batch = n_cached / n_mb
             report.isolated_per_batch = n_iso / n_mb
         return report
-
-    def _timed(self, it):
-        """Wrap a batch iterator, attributing wall time to meter.t_sample.
-
-        The store self-reports the host gather inside ``sample`` to
-        meter.t_slice and (sync-mode) cache builds inside ``start_epoch``
-        to meter.t_refresh; subtract both deltas so each second lands in
-        exactly one bucket.  Clamped at zero: an async build finishing
-        during a short window could otherwise over-subtract.
-        """
-        it = iter(it)
-        while True:
-            t0 = time.perf_counter()
-            slice0 = self.meter.t_slice
-            refresh0 = self.meter.t_refresh
-            try:
-                mb = next(it)
-            except StopIteration:
-                return
-            elapsed = time.perf_counter() - t0
-            self.meter.t_sample += max(
-                elapsed - (self.meter.t_slice - slice0)
-                - (self.meter.t_refresh - refresh0), 0.0)
-            yield mb
 
     # ------------------------------------------------------------------
     def evaluate(self, idx: Optional[np.ndarray] = None,

@@ -95,13 +95,15 @@ def _kernel(idx_ref, w_ref, feat_ref, out_ref):
 
 
 def gather_agg_pallas(feat: jax.Array, idx: jax.Array, w: jax.Array,
-                      block_d: int = 2048, interpret: bool = False) -> jax.Array:
+                      block_d: int = 2048, interpret: bool = False,
+                      name: str = "gather_agg") -> jax.Array:
     """out[b] = sum_k w[b,k] * feat[idx[b,k]].
 
     Args:
       feat: [N, D] feature/cache table (f32 or bf16).
       idx:  [B, K] int32 row indices (padded lanes must carry w == 0).
       w:    [B, K] f32 weights.
+      name: the kernel's name on the device (profiles, HLO).
     Returns [B, D] f32.
     """
     n, d = feat.shape
@@ -126,6 +128,7 @@ def gather_agg_pallas(feat: jax.Array, idx: jax.Array, w: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, 1, d), jnp.float32),
         interpret=interpret,
+        name=name,
     )
     feat3 = feat.reshape(n, 1, d)
     out = jax.lax.map(

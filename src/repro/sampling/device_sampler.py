@@ -61,7 +61,8 @@ class DeviceGNSSampler(GNSSampler):
         # replaces the host gather, so no neighbor lanes ship)
         self.pad_sizes = [(d0, d0)] + list(self.pad_sizes[1:])
 
-    def sample(self, targets: np.ndarray, rng: np.random.Generator) -> MiniBatch:
+    def _sample(self, targets: np.ndarray,
+                rng: np.random.Generator) -> MiniBatch:
         assert self.cache is not None, "call start_epoch/refresh_cache first"
         gen = self._gen
         assert gen.device_adj is not None, (

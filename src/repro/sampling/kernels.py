@@ -135,7 +135,8 @@ def slot_gather_agg_pallas(cache_table: jax.Array, lane_rows: jax.Array,
     lr = lane_rows.astype(jnp.int32)
     w_eff = jnp.where(lr >= 0, w.astype(jnp.float32), 0.0)
     return gather_agg_pallas(cache_table, jnp.maximum(lr, 0), w_eff,
-                             block_d=block_d, interpret=interpret)
+                             block_d=block_d, interpret=interpret,
+                             name="gns_sample_gather")
 
 
 # ---------------------------------------------------------------------------
