@@ -311,7 +311,8 @@ class GNSSampler:
         n_c = (self.cache_adj.indptr[ids + 1] - self.cache_adj.indptr[ids]).astype(np.float64)
 
         # 1) cached neighbors first (from the induced subgraph S)
-        c_nbrs, c_mask = self.cache_adj.sample_neighbors(ids, k, rng)
+        with self.store.span("repro.sample.draw"):
+            c_nbrs, c_mask = self.cache_adj.sample_neighbors(ids, k, rng)
         coeff = importance_coefficients(
             cache.probs[c_nbrs], cache.size, k, n_c[:, None],
             mode=self.cfg.importance_mode, lam=self._lam)
@@ -340,7 +341,8 @@ class GNSSampler:
         need = k - c_mask.sum(axis=1)
         rows = np.where((need > 0) & (deg - n_c > 0))[0]
         if len(rows):
-            t_nbrs, t_mask = g.sample_neighbors(ids[rows], k, rng)
+            with self.store.span("repro.sample.draw"):
+                t_nbrs, t_mask = g.sample_neighbors(ids[rows], k, rng)
             t_mask &= ~cache.in_cache[t_nbrs]            # rejection: non-cached only
             # keep at most `need` lanes per row
             lane_rank = np.cumsum(t_mask, axis=1)

@@ -27,6 +27,8 @@ trace's clock.  The training path's spans:
 * ``repro.train.fit``      — ``GNSEngine.fit``'s epoch loop (main thread);
 * ``repro.pipeline.wait``  — the step loop blocked on the prefetch queue;
 * ``repro.sample``         — one ``GNSSampler.sample`` call (prefetch thread);
+* ``repro.sample.draw``    — the ``CSRGraph.sample_neighbors`` calls of
+  ``GNSSampler._sample_layer`` (cache draw and top-up draw);
 * ``repro.sample.slice``   — the host feature slice in ``assemble_input``;
 * ``repro.train.put``      — the ``device_put`` call, which returns at enqueue;
 * ``repro.train.h2d``      — from the enqueue until every leaf of the copy is

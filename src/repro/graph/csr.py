@@ -10,7 +10,6 @@ importing this module never initializes a device backend.
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
 
 import numpy as np
 
@@ -71,14 +70,15 @@ class CSRGraph:
     # Batched neighbor access (sampler hot path)
     # ------------------------------------------------------------------
     def sample_neighbors(self, nodes: np.ndarray, k: int,
-                         rng: np.random.Generator,
-                         replace: Optional[bool] = None) -> tuple[np.ndarray, np.ndarray]:
+                         rng: np.random.Generator) -> tuple[np.ndarray, np.ndarray]:
         """Uniformly sample up to ``k`` neighbors for each node in ``nodes``.
 
         Returns ``(nbrs, mask)`` of shape (len(nodes), k), int32/bool.  Nodes
         with degree ``<= k`` get their full neighbor list (no replacement) and
         the remaining lanes masked out — matching DGL's ``sample_neighbors``
         semantics used by the paper's NS baseline.  Padded lanes hold 0.
+        Nodes with degree ``> k`` get a uniform k-subset of their neighbors
+        in uniformly random lane order.
         """
         nodes = np.asarray(nodes, dtype=np.int64)
         deg = self.indptr[nodes + 1] - self.indptr[nodes]
@@ -110,27 +110,7 @@ class CSRGraph:
             bn = nodes[big]
             bdeg = deg[big]
             rows = np.where(big)[0]
-            # Vectorized sampling without replacement via argpartition of
-            # random keys: generate (m, k) unique offsets per row using the
-            # Floyd-ish trick — random floats ranked per row.
-            # For rows with huge degree this is O(m*k) not O(m*deg).
-            r = rng.random((len(bn), k))
-            # map k uniform draws onto distinct offsets: draw k floats, scale
-            # to deg, resolve collisions by re-draw for the (rare) duplicates.
-            offs = (r * bdeg[:, None]).astype(np.int64)
-            # resolve duplicates within each row (cheap loop, rare)
-            for _ in range(4):
-                srt = np.sort(offs, axis=1)
-                dup = (srt[:, 1:] == srt[:, :-1]).any(axis=1)
-                if not dup.any():
-                    break
-                ridx = np.where(dup)[0]
-                offs[ridx] = (rng.random((len(ridx), k)) * bdeg[ridx][:, None]).astype(np.int64)
-            else:
-                # fall back to exact per-row choice for stubborn rows
-                ridx = np.where((np.sort(offs, 1)[:, 1:] == np.sort(offs, 1)[:, :-1]).any(1))[0]
-                for i in ridx:
-                    offs[i] = rng.choice(bdeg[i], size=k, replace=False)
+            offs = _floyd_offsets(bdeg, k, rng)
             out[rows] = self.indices[self.indptr[bn][:, None] + offs]
             mask[rows] = True
         return out, mask
@@ -157,3 +137,32 @@ class CSRGraph:
 @dataclasses.dataclass(frozen=True)
 class CacheAdjacency(CSRGraph):
     """CSR holding only cached neighbors — the induced subgraph S of §3.3."""
+
+
+def _floyd_offsets(deg: np.ndarray, k: int,
+                   rng: np.random.Generator) -> np.ndarray:
+    """A uniform k-subset of ``[0, deg)`` per row, for rows with deg > k.
+
+    Floyd's algorithm run column by column over every row at once: column
+    ``s`` draws ``t`` uniform on ``[0, deg - k + s]`` and keeps it unless an
+    earlier column already holds it, in which case it takes ``deg - k + s``
+    itself (new to the row, since earlier columns stay below it).  That is
+    O(m·k²) array work whatever the degree.  Floyd's column order is not
+    exchangeable (column 0 never exceeds ``deg - k``), and callers keep
+    lanes by position, so one random permutation per row shuffles the
+    columns: the lanes are then distributed as k i.i.d. draws conditioned on
+    being distinct.  Returns int64 [m, k].
+    """
+    m = len(deg)
+    cols = np.empty((k, m), dtype=np.int64)      # column-major: contiguous
+    r = rng.random((m, k))
+    for s in range(k):
+        j = deg - k + s
+        # floor(r·(j+1)) can round up to j+1 when r is within an ulp of 1
+        t = np.minimum((r[:, s] * (j + 1)).astype(np.int64), j)
+        seen = np.zeros(m, dtype=bool)
+        for prev in cols[:s]:
+            seen |= prev == t
+        cols[s] = np.where(seen, j, t)
+    perm = np.argsort(rng.random((m, k)), axis=1)
+    return np.take_along_axis(cols.T, perm, axis=1)

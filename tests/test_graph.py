@@ -68,6 +68,72 @@ def test_sample_neighbors_uniformity():
     assert np.allclose(freq, 1 / 8, atol=0.02)
 
 
+def _regular_rows(rows: int, deg: int) -> CSRGraph:
+    """``rows`` nodes of degree ``deg``; node r's neighbours are the next
+    ``deg`` ids after r (mod rows), so offset i of row r is (r + 1 + i)."""
+    indptr = np.arange(rows + 1, dtype=np.int64) * deg
+    r = np.repeat(np.arange(rows), deg)
+    indices = ((r + 1 + np.tile(np.arange(deg), rows)) % rows).astype(np.int32)
+    return CSRGraph(indptr=indptr, indices=indices)
+
+
+_DRAW_CASES = [(k, deg) for k in (5, 10, 15) for deg in (k + 1, 2 * k, 20 * k)]
+_DRAW_ROWS = 20_000
+
+
+def _draw_offsets(k: int, deg: int, seed: int) -> np.ndarray:
+    """One vectorized draw over many rows of degree ``deg``, mapped back to
+    neighbour offsets ``[0, deg)`` (int64 [rows, k])."""
+    g = _regular_rows(_DRAW_ROWS, deg)
+    nodes = np.arange(_DRAW_ROWS, dtype=np.int64)
+    nbrs, mask = g.sample_neighbors(nodes, k, np.random.default_rng(seed))
+    assert mask.all()
+    return (nbrs.astype(np.int64) - nodes[:, None] - 1) % _DRAW_ROWS
+
+
+@pytest.mark.parametrize("k,deg", _DRAW_CASES)
+def test_sample_neighbors_draw_distinct_in_list(k, deg):
+    # one call over rows of mixed degree: isolated, below, at and above k
+    degs = np.array([0, 1, k - 1, k, deg] * 400)
+    indptr = np.concatenate([[0], np.cumsum(degs)]).astype(np.int64)
+    n = len(degs)
+    row = np.repeat(np.arange(n), degs)
+    pos = np.arange(indptr[-1]) - indptr[row]
+    g = CSRGraph(indptr=indptr,
+                 indices=((row + 1 + pos) % n).astype(np.int32))
+    nodes = np.arange(n, dtype=np.int64)
+    nbrs, mask = g.sample_neighbors(nodes, k, np.random.default_rng(k * deg))
+    assert nbrs.shape == mask.shape == (n, k)
+    assert nbrs.dtype == np.int32 and mask.dtype == bool
+    np.testing.assert_array_equal(mask.sum(axis=1), np.minimum(degs, k))
+    assert (nbrs[~mask] == 0).all()
+    offs = (nbrs.astype(np.int64) - nodes[:, None] - 1) % n
+    assert (offs[mask] < np.broadcast_to(degs[:, None], mask.shape)[mask]).all()
+    srt = np.sort(np.where(mask, offs, -1 - np.arange(k)), axis=1)
+    assert (srt[:, 1:] != srt[:, :-1]).all()        # no lane repeats
+
+
+@pytest.mark.parametrize("k,deg", _DRAW_CASES)
+def test_sample_neighbors_inclusion_frequency(k, deg):
+    """Every neighbour is drawn with probability k/deg."""
+    offs = _draw_offsets(k, deg, seed=deg + 1)
+    counts = np.bincount(offs.ravel(), minlength=deg)
+    p = k / deg
+    z = (counts - _DRAW_ROWS * p) / np.sqrt(_DRAW_ROWS * p * (1 - p))
+    assert np.abs(z).max() < 5, z
+
+
+@pytest.mark.parametrize("k,deg", _DRAW_CASES)
+def test_sample_neighbors_lanes_exchangeable(k, deg):
+    """Lane 0 and lane k-1 are each uniform over the neighbours: callers
+    keep lanes by position, so no lane may favour any part of the list."""
+    from scipy.stats import chisquare
+    offs = _draw_offsets(k, deg, seed=2 * deg + 3)
+    for lane in (0, k - 1):
+        counts = np.bincount(offs[:, lane], minlength=deg)
+        assert chisquare(counts).pvalue > 1e-4, (lane, counts)
+
+
 def test_induced_cache_adjacency():
     g = powerlaw_graph(2000, avg_degree=8, seed=2)
     rng = np.random.default_rng(0)

@@ -171,6 +171,43 @@ def test_gns_aggregation_unbiased_zscore():
     assert (z < 5).mean() > 0.95, f"fraction within 5 SE: {(z < 5).mean():.3f}"
 
 
+def test_gns_topup_unbiased_when_truncation_binds():
+    """Upper-layer top-up: rows with 0 < N_C(v) < k keep only the first
+    ``need = k - N_C(v)`` non-cached lanes of a k-lane draw by lane rank,
+    and that cut binds whenever the draw misses a cached neighbour.  Given
+    the cache, E[Σ w·h] must still be the full-neighbourhood mean; a draw
+    whose lane order favours part of the neighbour list biases it.  ``h``
+    rises with the node id, as does each sorted neighbour list."""
+    from math import comb
+    k, trials = 10, 4000
+    g = powerlaw_graph(3000, avg_degree=12, seed=5)
+    h = (np.arange(g.num_nodes, dtype=np.float64) / g.num_nodes)[:, None]
+    cfg = SamplerConfig(fanouts=(k, k), batch_size=64,
+                        cache=CacheConfig(fraction=0.05, period=1))
+    s = GNSSampler(g, cfg, h.astype(np.float32),
+                   np.zeros(g.num_nodes, np.int32))
+    s.refresh_cache(np.random.default_rng(11), version=0)
+    deg = g.degrees
+    n_c = np.diff(s.cache_adj.indptr)
+    cand = np.where((n_c >= 1) & (n_c < k) & (deg >= k + 2))[0]
+    cand = cand[np.argsort(deg[cand], kind="stable")]
+    nodes = cand[::max(1, len(cand) // 32)][:32].astype(np.int64)
+    assert len(nodes) >= 16
+    # the cut binds unless all N_C(v) cached neighbours are among the k drawn
+    for v in nodes:
+        d, c = int(deg[v]), int(n_c[v])
+        assert comb(d - c, k - c) / comb(d, k) < 0.5, (d, c)
+    nbrs, mask, w = s._sample_layer(np.repeat(nodes, trials), k,
+                                    np.random.default_rng(3),
+                                    allow_topup=True)
+    est = (np.where(mask, w, 0.0) * h[nbrs, 0]).sum(axis=1)
+    est = est.reshape(len(nodes), trials)
+    target = full_neighbor_mean(g, h, nodes)[:, 0]
+    se = est.std(axis=1) / np.sqrt(trials)
+    z = (est.mean(axis=1) - target) / np.maximum(se, 1e-9)
+    assert np.abs(z).max() < 5, z
+
+
 @pytest.mark.slow
 def test_gns_variance_decreases_with_cache_size():
     """Theorem 1 trend: larger cache fraction C̃ -> smaller estimator MSE."""

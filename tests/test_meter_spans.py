@@ -188,6 +188,19 @@ def test_training_path_spans(tiny_ds):
     assert sp["repro.sample.slice"].wall_s < sp["repro.sample"].wall_s
 
 
+@pytest.mark.parametrize("backend", ["host", "device"])
+def test_draw_span_inside_sample(tiny_ds, backend):
+    """``repro.sample.draw`` books the neighbour draws of every batch on
+    the prefetch thread, inside ``repro.sample``."""
+    eng = _engine(tiny_ds, backend)
+    eng.fit(epochs=1, max_batches=3, prefetch=True)
+    sp = eng.meter.span_totals()
+    draw, sample = sp["repro.sample.draw"], sp["repro.sample"]
+    assert sample.count == 3
+    assert draw.count >= sample.count
+    assert draw.wall_s < sample.wall_s
+
+
 def test_training_spans_without_prefetch(tiny_ds):
     """The loader is iterated directly: sampling is timed on the main
     thread by its own span, and nothing waits on a queue."""
