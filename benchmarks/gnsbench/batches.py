@@ -1,5 +1,5 @@
 """Recorded program batches turned into the reference's, with the checks
-of the program's random choices on the way (see ``references/``).
+of the program's random choices on the way (see ``gnsref``).
 
 A batch is read through its attributes only (``device.blocks``,
 ``input_node_ids``, ...): nothing of the program is imported.
@@ -8,8 +8,10 @@ from __future__ import annotations
 
 import numpy as np
 
+from gnsbench.gnsref import Batch, Layer, check_lanes, device_draw
 
-def reference_batch(ref, cv, data: graphgen.BenchData, mb, cfg: dict,
+
+def reference_batch(cv, data: graphgen.BenchData, mb, cfg: dict,
                     backend: str, dtype=np.float64):
     """Turn one recorded batch into the reference's, checking the program's
     random choices on the way.  Returns ``(batch, bad, weight_gap, w_ref)``
@@ -30,7 +32,7 @@ def reference_batch(ref, cv, data: graphgen.BenchData, mb, cfg: dict,
         bad += int((w[d:] != 0).sum()) + int(d > n_src)
         if li == 0 and backend == "device":
             bad += int((w != 0).sum())          # placeholder block
-            lanes, wl, b, g, wr = _device_layer(ref, cv, ids[:d], dev, fan[0],
+            lanes, wl, b, g, wr = _device_layer(cv, ids[:d], dev, fan[0],
                                                 dtype)
             bad += b
             gap = max(gap, g)
@@ -40,8 +42,8 @@ def reference_batch(ref, cv, data: graphgen.BenchData, mb, cfg: dict,
             live = w > 0
             bad += int((live & ((idx < 0) | (idx >= n_src))).sum())
             lanes = ids[np.clip(idx, 0, n_src - 1)]
-            chk, wl = ref.check_lanes(cv, ids[:d], lanes, w, fan[li],
-                                      topup=li > 0, dtype=dtype)
+            chk, wl = check_lanes(cv, ids[:d], lanes, w, fan[li],
+                                  topup=li > 0, dtype=dtype)
             bad += chk.bad
             gap = max(gap, chk.weight_gap)
             w_all.append(wl)
@@ -51,13 +53,13 @@ def reference_batch(ref, cv, data: graphgen.BenchData, mb, cfg: dict,
             nodes, loc[live] = _positions(nodes, lanes[live])
         else:
             loc = np.where(wl != 0, idx, 0)
-        layers.append(ref.Layer(num_dst=d, idx=loc,
-                                w=np.asarray(wl, dtype=np.float64)))
+        layers.append(Layer(num_dst=d, idx=loc,
+                            w=np.asarray(wl, dtype=np.float64)))
         n_src = d
     b_real = int(np.asarray(dev.label_mask).sum())
-    batch = ref.Batch(x=data.features[nodes], layers=layers,
-                      labels=data.labels[ids[:b_real]].astype(np.int32),
-                      label_w=np.ones(b_real, np.float32))
+    batch = Batch(x=data.features[nodes], layers=layers,
+                  labels=data.labels[ids[:b_real]].astype(np.int32),
+                  label_w=np.ones(b_real, np.float32))
     return batch, bad, gap, w_all
 
 
@@ -71,7 +73,7 @@ def _positions(nodes: np.ndarray, query: np.ndarray
     return nodes, order[np.searchsorted(nodes[order], query)]
 
 
-def _device_layer(ref, cv, dst, dev, k, dtype):
+def _device_layer(cv, dst, dev, k, dtype):
     """The input layer of a device-backend batch: cached destinations drawn
     by the reference's own replica of the device draw, the others from the
     host fallback lanes the batch carries (checked like host lanes)."""
@@ -84,7 +86,7 @@ def _device_layer(ref, cv, dst, dev, k, dtype):
     w = np.zeros((n, k), dtype=dtype)
     key = np.asarray(dev.sample_key)[0]
     rows = np.nonzero(cached)[0]
-    lanes[rows], w[rows] = ref.device_draw(cv, dst[rows], rows, key, k, dtype)
+    lanes[rows], w[rows] = device_draw(cv, dst[rows], rows, key, k, dtype)
     unc = np.nonzero(~cached)[0]
     gap = 0.0
     wr = np.zeros((n, k), dtype=dtype)
@@ -93,8 +95,8 @@ def _device_layer(ref, cv, dst, dev, k, dtype):
         live = fb_w[unc] > 0
         bad += int((live & ((rows < 0) | (rows >= len(cv.ids)))).sum())
         fb_nodes = cv.ids[np.clip(rows, 0, len(cv.ids) - 1)]
-        chk, wl = ref.check_lanes(cv, dst[unc], fb_nodes, fb_w[unc], k,
-                                  topup=False, dtype=dtype)
+        chk, wl = check_lanes(cv, dst[unc], fb_nodes, fb_w[unc], k,
+                              topup=False, dtype=dtype)
         bad += chk.bad
         gap = chk.weight_gap
         lanes[unc], w[unc] = fb_nodes, wl

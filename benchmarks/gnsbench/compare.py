@@ -15,7 +15,7 @@ NEGLIGIBLE_GRAD = 1e-3
 
 
 def leaves(params) -> list[np.ndarray]:
-    """A layer list of ``{"w", "b"}`` dicts, flattened in a fixed order."""
+    """A layer list of dicts of arrays, flattened in a fixed order."""
     return [np.asarray(p[k], dtype=np.float64) for p in params
             for k in sorted(p)]
 

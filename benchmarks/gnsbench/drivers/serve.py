@@ -23,7 +23,7 @@ import time
 
 import numpy as np
 
-from gnsbench import batches, compare, graphgen, traffic
+from gnsbench import batches, compare, engine_model, gnsref, graphgen, traffic
 
 
 @dataclasses.dataclass
@@ -74,7 +74,7 @@ def _engine_config(cfg: dict, tr: dict, seed: int):
     from repro.core.sampler import SamplerConfig
     from repro.featurestore import CacheConfig
     from repro.gns import EngineConfig
-    from repro.gns.config import FabricConfig, ModelConfig, ServeConfig
+    from repro.gns.config import FabricConfig, ServeConfig
     return EngineConfig(
         sampler="gns",
         sampling=SamplerConfig(batch_size=cfg["batch_size"],
@@ -82,7 +82,7 @@ def _engine_config(cfg: dict, tr: dict, seed: int):
         cache=CacheConfig(fraction=cfg["cache_fraction"],
                           period=cfg["cache_period"],
                           strategy=cfg["cache_policy"]),
-        model=ModelConfig(hidden_dim=cfg["hidden_dim"]),
+        model=engine_model.model_config(cfg),
         serve=ServeConfig(buckets=tuple(tr["buckets"]),
                           max_wait_ms=tr["max_wait_ms"],
                           max_queue=tr["max_queue"],
@@ -249,13 +249,12 @@ def answer_mismatch(st: State) -> int:
 
 def check_inputs(st: State, dtype=np.float64):
     cfg, data = st.ctx.cell.config, st.data
-    ref = st.ctx.cell.reference
     size = int(data.num_nodes * cfg["cache_fraction"])
-    cv = ref.CacheView(data.indptr, data.indices, st.cache_ids, size)
+    cv = gnsref.CacheView(data.indptr, data.indices, st.cache_ids, size)
     bad = int(len(st.cache_ids) != size)
     out, gap, w_all = [], 0.0, []
     for i, mb in kept_batches(st):
-        b, nb, g, wl = batches.reference_batch(ref, cv, data, mb, cfg, "host",
+        b, nb, g, wl = batches.reference_batch(cv, data, mb, cfg, "host",
                                                dtype)
         n = len(b.labels)
         out.append((b, np.asarray(st.rec.served[i][1])[:n]))
@@ -266,10 +265,8 @@ def check_inputs(st: State, dtype=np.float64):
 
 
 def reference_params0(st: State):
-    cfg = st.ctx.cell.config
-    return st.ctx.cell.reference.init_params(
-        st.ctx.engine_seed, cfg["feat_dim"], cfg["hidden_dim"],
-        cfg["num_classes"], len(cfg["fanouts"]))
+    return st.ctx.cell.reference.init_params(st.ctx.engine_seed,
+                                             st.ctx.cell.config)
 
 
 def check(st: State) -> dict:

@@ -20,7 +20,7 @@ import time
 
 import numpy as np
 
-from gnsbench import batches, compare, counts, graphgen
+from gnsbench import batches, compare, counts, engine_model, gnsref, graphgen
 
 
 @dataclasses.dataclass
@@ -59,7 +59,6 @@ def _engine_config(cfg: dict, tr: dict, seed: int):
     from repro.core.sampler import SamplerConfig
     from repro.featurestore import CacheConfig
     from repro.gns import EngineConfig
-    from repro.gns.config import ModelConfig
     from repro.optim.adam import AdamConfig
     return EngineConfig(
         sampler="gns",
@@ -69,8 +68,7 @@ def _engine_config(cfg: dict, tr: dict, seed: int):
         cache=CacheConfig(fraction=cfg["cache_fraction"],
                           period=cfg["cache_period"],
                           strategy=cfg["cache_policy"]),
-        model=ModelConfig(hidden_dim=cfg["hidden_dim"],
-                          input_impl=tr["input_impl"]),
+        model=engine_model.model_config(cfg, input_impl=tr["input_impl"]),
         optim=AdamConfig(lr=cfg["lr"], b1=cfg["adam_b1"], b2=cfg["adam_b2"],
                          eps=cfg["adam_eps"]),
         seed=seed, prefetch=True)
@@ -80,12 +78,11 @@ def _real_dst(mb) -> list:
     return [int(np.asarray(b.dst_mask).sum()) for b in mb.device.blocks]
 
 
-def instrument(eng, rec: Recorder, span, cfg: dict) -> None:
-    """Wrap the engine's step, copy and sampling calls (module doc)."""
+def instrument(eng, rec: Recorder, span, cfg: dict, ref) -> None:
+    """Wrap the engine's step, copy and sampling calls (module doc); each
+    step's FLOPs are the reference ``ref``'s count."""
     import jax
     run_batch, put, sample = eng.run_batch, eng._put_batch, eng.sampler.sample
-    fan = list(cfg["fanouts"])
-    dims = (cfg["feat_dim"], cfg["hidden_dim"], cfg["num_classes"])
 
     def step(mb, home_shards=None):
         with span("step"):
@@ -94,7 +91,7 @@ def instrument(eng, rec: Recorder, span, cfg: dict) -> None:
         rec.steps += 1
         rec.seeds += int(np.asarray(mb.device.label_mask).sum())
         rec.h2d_bytes += counts.tree_nbytes(mb.device)
-        rec.flops += counts.sage_train_flops(_real_dst(mb), fan, *dims)
+        rec.flops += ref.train_flops(_real_dst(mb), cfg)
         if rec.steps <= rec.capture:
             rec.batches.append(mb)
             rec.losses.append(float(out[0]))
@@ -127,7 +124,7 @@ def setup(ctx) -> State:
                     dataset=graphgen.as_program_dataset(data, cfg["name"]))
     ctx.log(f"engine built in {time.perf_counter() - t0:.2f}s")
     rec = Recorder(capture=tr["check_steps"])
-    instrument(eng, rec, ctx.span, cfg)
+    instrument(eng, rec, ctx.span, cfg, ctx.cell.reference)
     params0 = jax.device_get(eng.params)
     warm = tr["warmup_steps"]
     t0 = time.perf_counter()
@@ -191,17 +188,16 @@ def release(st: State) -> None:
 def check_inputs(st: State, dtype=np.float64):
     """The cache view, the reference batches and the sampler checks."""
     cfg, data = st.ctx.cell.config, st.data
-    ref = st.ctx.cell.reference
     size = int(data.num_nodes * cfg["cache_fraction"])
     ids = st.cache_ids
     bad = int(len(ids) != size or len(np.unique(ids)) != len(ids)
               or ids.min() < 0 or ids.max() >= data.num_nodes)
-    cv = ref.CacheView(data.indptr, data.indices, ids, size)
+    cv = gnsref.CacheView(data.indptr, data.indices, ids, size)
     backend = st.ctx.cell.traffic["sampler_backend"]
     out, gap, w_all = [], 0.0, []
     for mb in st.rec.batches:
-        b, nb, g, wl = batches.reference_batch(ref, cv, data, mb, cfg,
-                                               backend, dtype)
+        b, nb, g, wl = batches.reference_batch(cv, data, mb, cfg, backend,
+                                               dtype)
         out.append(b)
         bad += nb
         gap = max(gap, g)
@@ -210,10 +206,8 @@ def check_inputs(st: State, dtype=np.float64):
 
 
 def reference_params0(st: State):
-    cfg = st.ctx.cell.config
-    return st.ctx.cell.reference.init_params(
-        st.ctx.engine_seed, cfg["feat_dim"], cfg["hidden_dim"],
-        cfg["num_classes"], len(cfg["fanouts"]))
+    return st.ctx.cell.reference.init_params(st.ctx.engine_seed,
+                                             st.ctx.cell.config)
 
 
 def program_outputs(st: State) -> dict:

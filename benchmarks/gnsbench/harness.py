@@ -11,6 +11,27 @@ in a file of its own, found by the name ``BENCHMARK.json`` gives it:
 * ``metrics/<metric>.py``    — one reader per per-layer metric;
 * ``limits/<cell>.json``     — the limit of each number ``check`` compares.
 
+A configuration selects its architecture with two keys: ``reference``
+names the reference module, and ``engine_model`` (optional) holds the
+fields of the program's ``ModelConfig`` besides ``hidden_dim`` and the
+traffic's ``input_impl`` (``engine_model.model_config``).  The reference
+module is the architecture's contract with the harness; the drivers call
+nothing else of it:
+
+* ``init_params(seed, cfg)`` — the parameters before the first step, a
+  list of one dict of arrays per layer, in the layout the program keeps
+  under ``params["layers"]``;
+* ``loss(params, batch, dtype)`` — the mean loss over a ``gnsref.Batch``;
+* ``train(params0, batches, opt, dtype)`` — Adam over the batches
+  (``gnsref.train`` on the architecture's loss): the step losses, the first
+  gradient and the parameters after the last step;
+* ``logits(params, batch, dtype)`` — the output rows of a batch's targets;
+* ``train_flops(real_dst, cfg)`` — the FLOPs one training step requires,
+  from the real destination rows of each layer (what ``train_mfu`` reads).
+
+The GNS sampling replica every reference shares, and the batch format,
+live in ``gnsref``.
+
 A run: refuse unless JAX finds the cell's TPU chips; ``setup`` (data, the
 system under test, compiles, the first steps the check follows); measure
 for ``--seconds`` with tracing off (end-to-end metrics) or on (per-layer
@@ -39,6 +60,10 @@ TRACE_DIR = HERE / ".cache" / "trace"
 
 def log(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
+
+
+# What the drivers call of a configuration's reference module.
+REFERENCE_CONTRACT = ("init_params", "loss", "train", "logits", "train_flops")
 
 
 class Refused(RuntimeError):

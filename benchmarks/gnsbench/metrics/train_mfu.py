@@ -1,7 +1,7 @@
 """The whole training step's share of the chip's bf16 peak: the FLOPs the
 forward and backward passes require on the real (unpadded) rows of every
-step in the window (``counts.sage_train_flops``), over the traced window
-times the chips times the peak."""
+step in the window (the configured reference's ``train_flops``), over the
+traced window times the chips times the peak."""
 LAYER = "compiled step"
 SOURCE = "device_trace"
 MOVES = "train_seeds_per_s"

@@ -1,8 +1,12 @@
-"""The operation and byte counters against hand counts."""
+"""The operation and byte counters against hand counts, and the FLOP total
+a training run takes from its reference."""
+import dataclasses
+import types
+
 import numpy as np
 import pytest
 
-from gnsbench import counts, peaks
+from gnsbench import counts, harness, peaks
 from repro.core.minibatch import DeviceBatch, LayerBlock, block_pad_sizes
 
 
@@ -75,3 +79,47 @@ def test_gather_cost_and_roofline():
 def test_unknown_device_kind_raises():
     with pytest.raises(KeyError, match="no published peaks"):
         peaks.peaks_for("TPU v9 imaginary")
+
+
+def counting(ref):
+    """The contract's functions of ``ref``, with its ``init_params`` and
+    ``train_flops`` calls recorded."""
+    calls = {"init_params": 0, "train_flops": []}
+    mod = types.SimpleNamespace(**{fn: getattr(ref, fn)
+                                   for fn in harness.REFERENCE_CONTRACT})
+
+    def init_params(seed, cfg):
+        calls["init_params"] += 1
+        return ref.init_params(seed, cfg)
+
+    def train_flops(real_dst, cfg):
+        flops = ref.train_flops(real_dst, cfg)
+        calls["train_flops"].append((list(real_dst), flops))
+        return flops
+    mod.init_params, mod.train_flops = init_params, train_flops
+    return mod, calls
+
+
+@pytest.mark.parametrize("name", ["products_train", "papers_train_device"])
+def test_flops_come_from_the_reference(tiny_cell, cpu, name):
+    cell = tiny_cell(name)
+    ref, calls = counting(cell.reference)
+    cell = dataclasses.replace(cell, reference=ref)
+    ctx = harness.Context(cell=cell, seed=2718281828, devices=cpu,
+                          log=lambda msg: None)
+    st = cell.driver.setup(ctx)
+    warm = len(calls["train_flops"])
+    assert warm == cell.traffic["warmup_steps"]
+    m = cell.driver.measure(st, 1.0, False)
+    window = [f for _, f in calls["train_flops"][warm:]]
+    assert len(window) == m.record["steps"] > 0
+    assert m.record["flops"] == sum(window)
+    cfg = cell.config
+    for real_dst, flops in calls["train_flops"]:     # graphsage's own count
+        assert flops == counts.sage_train_flops(
+            real_dst, cfg["fanouts"], cfg["feat_dim"],
+            cfg["hidden_dim"], cfg["num_classes"])
+    cell.driver.release(st)
+    nums = cell.driver.check(st)
+    assert calls["init_params"] == 1
+    assert all(nums[k] <= lim for k, lim in cell.limits.items()), nums

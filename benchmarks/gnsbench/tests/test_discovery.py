@@ -80,7 +80,8 @@ def test_check_fits_its_budget_with_24_cells():
 def test_cell_found_by_name(name):
     cell = harness.load_cell(name)
     assert callable(cell.driver.setup) and callable(cell.driver.check)
-    assert callable(cell.reference.init_params)
+    for fn in harness.REFERENCE_CONTRACT:
+        assert callable(getattr(cell.reference, fn)), fn
     assert cell.limits and all(v >= 0 for v in cell.limits.values())
     for entry in cell.per_layer:
         mod = cell.readers[entry["name"]]
